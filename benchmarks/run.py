@@ -434,6 +434,9 @@ def main(argv=None) -> None:
              "BENCH_maps.quick.json under --quick)",
     )
     ns = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     out = ns.out or ("BENCH_maps.quick.json" if ns.quick else
                      "BENCH_maps.json")
 

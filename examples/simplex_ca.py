@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.kernels import ops
 from repro.kernels import ref as R
 
@@ -154,6 +155,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=3)
     ap.add_argument("--ckpt-dir", type=str, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.devices:
         sharded_demo(args)

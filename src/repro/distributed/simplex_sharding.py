@@ -443,7 +443,7 @@ class ShardedSimplexCA:
         from repro.kernels.engine import SimplexKernel, default_rho
 
         self.m, self.n, self.k = m, n, k
-        self.rho = default_rho(m) if rho is None else rho
+        self.rho = default_rho(m, interpret) if rho is None else rho
         if n % self.rho != 0:
             raise ValueError(f"rho={self.rho} must divide n={n}")
         self.nb = n // self.rho
@@ -480,12 +480,14 @@ class ShardedSimplexCA:
             self._masks = masks
         return self._masks
 
-    def step_engine(self, state):
-        """One CA generation via per-shard engine launches + stitching."""
-        import jax
-        import jax.numpy as jnp
+    def shard_outputs(self, state):
+        """Each shard's engine launch on ``state``, one array per shard.
 
-        masks = self._ownership_masks()
+        With a mesh, shard i runs on mesh device i modulo the mesh
+        size, and its output stays there.
+        """
+        import jax
+
         outs = []
         for i, kern in enumerate(self._kernels):
             x = state
@@ -494,6 +496,15 @@ class ShardedSimplexCA:
                     state, self._devices[i % len(self._devices)]
                 )
             outs.append(kern(x))
+        return outs
+
+    def step_engine(self, state):
+        """One CA generation via per-shard engine launches + stitching."""
+        import jax
+        import jax.numpy as jnp
+
+        masks = self._ownership_masks()
+        outs = self.shard_outputs(state)
         out = state
         for y, mask in zip(outs, masks):
             if self._devices is not None:
@@ -545,7 +556,6 @@ def _spmd_step_fn(m: int, n: int, k: int, mesh, axis: str):
 
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     key = (m, n, k, axis, tuple(d.id for d in mesh.devices.flat))
@@ -609,7 +619,8 @@ def _spmd_step_fn(m: int, n: int, k: int, mesh, axis: str):
         return jnp.where(msk, new, local)
 
     fn = jax.jit(
-        shard_map(_step, mesh=mesh, in_specs=spec, out_specs=spec)
+        jax.shard_map(_step, mesh=mesh, in_specs=spec, out_specs=spec,
+                      check_vma=False)
     )
     _SPMD_CACHE[key] = fn
     return fn

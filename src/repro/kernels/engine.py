@@ -22,16 +22,19 @@ established, dimension-generically:
   block flush never clobbers live data; in-place semantics come from
   input/output aliasing of the body's *seed* array.
 * **3^m halo subsystem** — bodies that declare ``halo = True`` receive
-  a ``(3*rho,)*m`` neighborhood assembled from 3^m shifted input refs
-  (the standard Pallas stencil pattern — no element-offset reads on
-  TPU), each tile masked by the domain predicate of its own position.
+  the 3^m tiles fetched by shifted input refs (the standard Pallas
+  stencil pattern — no element-offset reads on TPU), each masked by
+  the domain predicate of its own position, and read element-shifted
+  windows of them built from tile rotates and edge selects.
   Boundary handling is per body and dimension: ``'periodic'`` wraps
   block coordinates mod nb (the 2-simplex CA convention), ``'free'``
   clamps reads at the domain edge and masks by true coordinates (the
   m >= 3 convention).
 * **Execution policy** — ``interpret=None`` resolves through
   ``kernels/policy.py`` per backend; block shapes are checked against
-  the Mosaic tiling contract before compiled launches.
+  the Mosaic tiling contract before compiled launches, and compiled
+  launches whose double-buffered blocks outgrow Mosaic's default
+  scoped VMEM ask for a limit sized to them.
 * **Launch splitting** — ``kind='composite'`` schedules can launch one
   ``pallas_call`` per piece (``split=``, autotuned default); the engine
   refuses the split for halo bodies, whose neighbor reads make
@@ -66,7 +69,12 @@ from jax.experimental import pallas as pl
 
 from repro.core.schedule import SimplexSchedule, resolve_kind
 
-from .policy import check_tile_alignment, resolve_interpret
+from .policy import (
+    aligned_rho,
+    check_tile_alignment,
+    resolve_interpret,
+    vmem_limit_bytes,
+)
 
 __all__ = [
     "SimplexKernel",
@@ -126,17 +134,20 @@ def pallas_launch(kernel, *, interpret: Optional[bool] = None, **kwargs):
 # ---------------------------------------------------------------------------
 
 
-def default_rho(m: int) -> int:
-    """The per-dimension default tile side the legacy kernels used.
+def default_rho(m: int, interpret: Optional[bool] = None) -> int:
+    """The per-dimension default tile side, aligned for the launch mode.
 
     Args:
         m: Simplex dimension.
+        interpret: Pallas mode (None = policy default).
 
     Returns:
-        8 at m=2, 4 at m=3, 2 at m >= 4 — small enough that the
-        interpret-mode test sweeps stay fast, overridable everywhere.
+        Under interpret mode 8 at m=2, 4 at m=3, 2 at m >= 4 — small
+        enough that the test sweeps stay fast.  On the compiled path the
+        same sides rounded up by ``policy.aligned_rho`` to the 128-lane
+        tile Mosaic accepts.
     """
-    return {2: 8, 3: 4}.get(m, 2)
+    return aligned_rho({2: 8, 3: 4}.get(m, 2), interpret)
 
 
 def domain_mask(m: int, n: int, coords: Sequence) -> jax.Array:
@@ -354,8 +365,10 @@ class BodyContext:
         valid: Traced schedule validity flag for this grid step.
         center: Raw center tile of the seed array (``(rho,)*m``) — also
             the out-of-domain fallback value the engine writes.
-        neighborhood: Masked ``(3*rho,)*m`` halo assembly, or None for
-            bodies with ``halo = False``.
+        neighbors: For ``halo = True`` bodies, the 3^m fetched tiles
+            keyed by block offset, each masked by the domain predicate
+            of its own position; None otherwise.  Read them through
+            ``window``.
         extras: Tuple of refs for the body's extra operands.
     """
 
@@ -367,7 +380,7 @@ class BodyContext:
     blocks: tuple
     valid: object
     center: object
-    neighborhood: object
+    neighbors: object
     extras: tuple
 
     def coords(self):
@@ -377,6 +390,24 @@ class BodyContext:
     def mask(self):
         """In-domain-and-valid element mask of this tile."""
         return domain_mask(self.m, self.n, self.coords()) & self.valid
+
+    def window(self, d: Tuple[int, ...]):
+        """The ``(rho,)*m`` tile seen at element offset ``d``.
+
+        Element ``i`` of the result is the masked halo value at
+        ``i + d`` (each ``|d_j| <= 1``).  It is built from the centre
+        tile and the neighbour tiles that offset reaches, each rotated
+        by ``pltpu.roll`` and chosen per element by an edge select:
+        only whole-tile rotates and selects, which Mosaic lowers on the
+        chip (no dynamic slices of a larger assembly).
+
+        Args:
+            d: Element offset, one entry per array axis.
+
+        Returns:
+            The shifted view, same shape and dtype as the tiles.
+        """
+        return _window(self.neighbors, d, self.rho, self.m)
 
 
 class KernelBody:
@@ -390,8 +421,8 @@ class KernelBody:
 
     Class attributes:
         name: Registry key.
-        halo: True to receive the 3^m neighborhood in
-            ``BodyContext.neighborhood``.
+        halo: True to receive the 3^m neighbour tiles in
+            ``BodyContext.neighbors`` (read through ``window``).
         element_local: True when per-piece launch splitting is sound
             (no cross-tile reads).
     """
@@ -562,17 +593,16 @@ def _launch_domain(kernel: "SimplexKernel", body: KernelBody, x):
             coords, valid = out[:-1], out[-1]
             blocks = tuple(coords[::-1])
 
-            neighborhood = None
+            neighbors = None
             if body.halo:
-                neighborhood = _assemble_halo(
-                    halo_refs, shifts, blocks, m, n, nb, rho,
-                    boundary, dtype,
+                neighbors = _halo_tiles(
+                    halo_refs, shifts, blocks, m, n, nb, rho, boundary,
                 )
             centre = halo_refs[centre_idx][...]
             ctx = BodyContext(
                 m=m, n=n, nb=nb, rho=rho, dtype=dtype,
                 blocks=blocks, valid=valid, center=centre,
-                neighborhood=neighborhood, extras=tuple(extra_refs),
+                neighbors=neighbors, extras=tuple(extra_refs),
             )
             o_ref[...] = jnp.where(
                 ctx.mask(), body.tile(ctx), centre
@@ -580,47 +610,103 @@ def _launch_domain(kernel: "SimplexKernel", body: KernelBody, x):
 
         grid_spec, args = _grid_spec(table, sched.grid, in_specs, out_spec)
         alias_src = len(args) + centre_idx
+        block_bytes = (len(shifts) + 1) * rho**m * dtype.itemsize + sum(
+            rho * e.shape[1] * e.dtype.itemsize for e in extras
+        )
         padded = pallas_launch(
             _kernel_fn,
             interpret=interpret,
             out_shape=jax.ShapeDtypeStruct(padded.shape, dtype),
             grid_spec=grid_spec,
             input_output_aliases={alias_src: 0},
+            **_compiler_params(interpret, block_bytes, rho**m * 4),
         )(*args, *([padded] * len(shifts)), *extras)
     return padded[:n]
 
 
-def _assemble_halo(halo_refs, shifts, blocks, m, n, nb, rho, boundary,
-                   dtype):
-    """Build the masked ``(3*rho,)*m`` neighborhood of the current tile.
+def _compiler_params(interpret: bool, block_bytes: int,
+                     tile_bytes: int) -> dict:
+    """``compiler_params`` for a compiled launch (empty when interpreting).
 
-    Each of the 3^m shifted refs is masked by the domain predicate of
-    ITS OWN position — wrapped coordinates under 'periodic' (matching
-    the roll-of-masked-state reference semantics), true coordinates
-    plus in-range checks under 'free' (clamp duplicates are inert) —
-    then placed into the big array at its stencil offset.
+    Args:
+        interpret: Resolved launch mode.
+        block_bytes: Bytes of one grid step's blocks, inputs and output.
+        tile_bytes: Bytes of one 32-bit ``(rho,)*m`` temporary.
+
+    Returns:
+        Keyword arguments for ``pallas_launch``.
     """
-    big = jnp.zeros((3 * rho,) * m, dtype=dtype)
+    if interpret:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+
+    limit = vmem_limit_bytes(block_bytes, tile_bytes)
+    if limit is None:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=limit)}
+
+
+def _halo_tiles(halo_refs, shifts, blocks, m, n, nb, rho, boundary):
+    """The masked 3^m neighbour tiles of the current tile, by offset.
+
+    Each shifted ref is masked by the domain predicate of ITS OWN
+    position — wrapped coordinates under 'periodic' (matching the
+    roll-of-masked-state reference semantics), true coordinates plus
+    in-range checks under 'free' (clamp duplicates are inert).
+    """
+    tiles = {}
     shape = (rho,) * m
     for si, d in enumerate(shifts):
         t = halo_refs[si][...]
         if boundary == "periodic":
-            tile_blocks = [
-                (b + dj) % nb for b, dj in zip(blocks, d)
-            ]
-            g = _axis_coords(tile_blocks, rho, shape)
-            ok = domain_mask(m, n, g)
+            tile_blocks = [(b + dj) % nb for b, dj in zip(blocks, d)]
+            ok = domain_mask(m, n, _axis_coords(tile_blocks, rho, shape))
         else:
             tile_blocks = [b + dj for b, dj in zip(blocks, d)]
             g = _axis_coords(tile_blocks, rho, shape)
             ok = domain_mask(m, n, g)
             for gj in g:
                 ok = ok & (gj >= 0) & (gj < n)
-        t = jnp.where(ok, t, 0)
-        big = jax.lax.dynamic_update_slice(
-            big, t, tuple((dj + 1) * rho for dj in d)
-        )
-    return big
+        tiles[d] = jnp.where(ok, t, 0)
+    return tiles
+
+
+def _window(tiles, d, rho, m):
+    """Shifted view of a halo: element ``i`` reads position ``i + d``.
+
+    Position ``i_j + d_j`` lies in the centre tile along axis j unless
+    it steps over the edge, where it lies in the neighbour tile at
+    block offset ``d_j``; within whichever tile it lies, its index is
+    ``(i_j + d_j) mod rho`` — a rotate of that tile.  One candidate per
+    combination of axes that step over, chosen by edge masks.
+    """
+    from jax.experimental.pallas import tpu as pltpu
+
+    shape = (rho,) * m
+    idx = [jax.lax.broadcasted_iota(jnp.int32, shape, j) for j in range(m)]
+    over = [
+        (idx[j] == rho - 1) if dj > 0 else (idx[j] == 0)
+        for j, dj in enumerate(d) if dj
+    ]
+    moving = [j for j, dj in enumerate(d) if dj]
+    out = None
+    for picks in itertools.product((False, True), repeat=len(moving)):
+        e = list((0,) * m)
+        for j, pick in zip(moving, picks):
+            if pick:
+                e[j] = d[j]
+        t = tiles[tuple(e)]
+        for j in moving:
+            t = pltpu.roll(t, (rho - d[j]) % rho, j)
+        if out is None:
+            out = t
+            continue
+        sel = None
+        for o, pick in zip(over, picks):
+            c = o if pick else jnp.logical_not(o)
+            sel = c if sel is None else sel & c
+        out = jnp.where(sel, t, out)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -703,19 +789,14 @@ class CABody(KernelBody):
     element_local = False
 
     def tile(self, ctx: BodyContext):
-        """Decode centre + neighbour count from the halo assembly."""
-        m, rho = ctx.m, ctx.rho
-        big = ctx.neighborhood
-        centre = jax.lax.dynamic_slice(
-            big, (rho,) * m, (rho,) * m
-        )
-        neigh = jnp.zeros((rho,) * m, dtype=big.dtype)
+        """Masked centre + neighbour count from the halo windows."""
+        m = ctx.m
+        centre = ctx.window((0,) * m)
+        neigh = jnp.zeros_like(centre)
         for d in itertools.product((-1, 0, 1), repeat=m):
             if d == (0,) * m:
                 continue
-            neigh = neigh + jax.lax.dynamic_slice(
-                big, tuple(rho + dj for dj in d), (rho,) * m
-            )
+            neigh = neigh + ctx.window(d)
         born = (centre == 0) & (neigh == 3)
         survive = (centre == 1) & ((neigh == 2) | (neigh == 3))
         return (born | survive).astype(ctx.dtype)
@@ -871,7 +952,7 @@ class SimplexKernel:
             raise ValueError(f"unknown executor {executor!r}")
         self.body = get_body(body)
         self.m = m
-        self.rho = default_rho(m) if rho is None else rho
+        self.rho = default_rho(m, interpret) if rho is None else rho
         self.kind = kind
         self.interpret = interpret
         self.split = split

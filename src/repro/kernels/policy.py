@@ -36,6 +36,7 @@ __all__ = [
     "check_tile_alignment",
     "tile_alignment_ok",
     "aligned_rho",
+    "vmem_limit_bytes",
     "TPU_SUBLANE",
     "TPU_LANE",
 ]
@@ -43,6 +44,14 @@ __all__ = [
 # Mosaic tiling contract for f32/int32 blocks: (sublane, lane) minimums.
 TPU_SUBLANE = 8
 TPU_LANE = 128
+
+# Mosaic's default scoped-VMEM limit on v5e, and the most a kernel asks
+# for: v5e has 128 MiB of VMEM per core; the rest stays with the compiler.
+SCOPED_VMEM_DEFAULT = 16 * 2**20
+SCOPED_VMEM_MAX = 100 * 2**20
+# (rho,)*m 32-bit temporaries a body keeps live beside its blocks (the
+# domain-mask iotas, their sum, the tile value and the select).
+BODY_TEMPS = 6
 
 _ENV = "REPRO_INTERPRET"
 _TRUE = ("1", "true", "yes", "on")
@@ -180,3 +189,32 @@ def aligned_rho(rho: int, interpret: Optional[bool] = None) -> int:
     if resolve_interpret(interpret):
         return rho
     return ((rho + TPU_LANE - 1) // TPU_LANE) * TPU_LANE
+
+
+def vmem_limit_bytes(block_bytes: int, tile_bytes: int) -> Optional[int]:
+    """Scoped-VMEM limit a compiled launch needs, or None for the default.
+
+    Pallas double-buffers every block, and a body keeps a few
+    tile-sized temporaries live beside them.  At m = 3 and rho = 128 one
+    int32 tile is 8 MiB, so even the single-input ACCUM body outgrows
+    Mosaic's 16 MiB default; the launch then asks for what it needs, up
+    to ``SCOPED_VMEM_MAX``.
+
+    Args:
+        block_bytes: Bytes of one grid step's blocks (inputs + output).
+        tile_bytes: Bytes of one 32-bit tile temporary.
+
+    Returns:
+        ``vmem_limit_bytes`` for ``CompilerParams``, or None when the
+        default suffices.
+
+    Example:
+        >>> vmem_limit_bytes(2 * 128 * 128 * 4, 128 * 128 * 4) is None
+        True
+        >>> vmem_limit_bytes(2 * 128**3 * 4, 128**3 * 4) // 2**20
+        100
+    """
+    need = 2 * block_bytes + BODY_TEMPS * tile_bytes
+    if need <= SCOPED_VMEM_DEFAULT:
+        return None
+    return min(need + need // 4, SCOPED_VMEM_MAX)

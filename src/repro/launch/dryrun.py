@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST be the first two lines, before any jax import: jax locks the
-#   device count at first initialization.
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this prints/records:
@@ -19,6 +15,7 @@ Usage:
 
 import argparse
 import json
+import os
 import time
 import traceback
 
@@ -143,6 +140,13 @@ def _write(outdir, mesh_name, arch, shape_name, rec, overrides=None):
 
 
 def main():
+    # 512 host devices stand in for the pod.  Set here, not on import,
+    # and added to the caller's XLA_FLAGS: jax fixes the device count
+    # when it first initializes, which happens after this in run_cell.
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + " --xla_force_host_platform_device_count=512"
+    ).strip()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

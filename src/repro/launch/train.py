@@ -43,11 +43,14 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     from repro.checkpoint import checkpointing as ckpt
+    from repro.compile_cache import enable_compile_cache
     from repro.configs.ALL import REDUCED
     from repro.configs.base import get_config
     from repro.data.pipeline import SyntheticLM
     from repro.models.model import Model
     from repro.optim.optimizer import make_optimizer, warmup_cosine
+
+    enable_compile_cache()
 
     cfg = REDUCED[args.arch]() if args.smoke else get_config(args.arch)
     over = {"act_dtype": "float32", "param_dtype": "float32", "remat": "none"}

@@ -14,6 +14,7 @@ XLA realization used by the distributed model.
 
 from __future__ import annotations
 
+import warnings
 from functools import partial
 from typing import Optional, Tuple
 
@@ -222,9 +223,12 @@ def simplex_attention(
     decision (roofline prior, measured ATTN rows when available, with
     the interpret step cap as a safety valve).
 
-    Structural guards force the chunked path regardless of ``impl``:
+    Only ``impl='auto'`` may run the chunked path in place of the
+    kernel, and it says so with a ``UserWarning`` naming the reason:
     MLA-style ``v_head_dim != qk head_dim`` (the flash kernel assumes
-    square tiles over one head dim) and ragged GQA group sizes.  The
+    square tiles over one head dim), ragged GQA group sizes, no tile
+    that divides the sequence, or a decision for chunked.  A forced
+    ``'flash*'`` impl that the kernel cannot map raises instead.  The
     decode strip stays on ``decode_attention`` — a 1-token query has
     no simplex to fold (see the §8 decode-exclusion rationale).
 
@@ -233,9 +237,7 @@ def simplex_attention(
         k: Keys (B, Hkv, S, D); Hq must be a multiple of Hkv (GQA).
         v: Values (B, Hkv, S, Dv).
         impl: 'auto' | 'flash' | 'chunked', plus the benchmark knobs
-            'flash-folded' / 'flash-bb' forcing the kernel schedule
-            (any forced flash still falls back when the kernel cannot
-            map the shape).
+            'flash-folded' / 'flash-bb' forcing the kernel schedule.
         chunk: Chunk size for the XLA path.
         schedule: 'folded' | 'bb' for the XLA path.
         scale: Score scale; None = D**-0.5.
@@ -243,6 +245,10 @@ def simplex_attention(
 
     Returns:
         Attention output, (B, Hq, S, Dv), in q's dtype.
+
+    Raises:
+        ValueError: Unknown ``impl``, or a forced flash impl on a shape
+            the kernel cannot map.
     """
     if impl not in ("auto", "flash", "chunked", "flash-folded", "flash-bb"):
         raise ValueError(
@@ -251,17 +257,22 @@ def simplex_attention(
         )
     b, hq, s, d = q.shape
     hkv = k.shape[1]
-    flash_able = (
-        impl != "chunked" and v.shape[-1] == d and hkv > 0 and hq % hkv == 0
-    )
-    if flash_able:
-        from repro.autotune import choose_attn_impl
+    if impl != "chunked":
+        dec = None
+        if v.shape[-1] != d:
+            why = f"value head dim {v.shape[-1]} != qk head dim {d}"
+        elif hkv == 0 or hq % hkv:
+            why = f"{hq} query heads do not group over {hkv} kv heads"
+        else:
+            from repro.autotune import choose_attn_impl
 
-        dec = choose_attn_impl(s, hq, d)
-        use_flash = dec.block_q > 0 and (
-            dec.impl == "flash" if impl == "auto" else True
-        )
-        if use_flash:
+            dec = choose_attn_impl(s, hq, d)
+            why = None
+            if dec.block_q == 0:
+                why = f"no flash tile divides s={s} at head dim {d}"
+            elif impl == "auto" and dec.impl != "flash":
+                why = f"the decision is {dec.impl} (source {dec.source})"
+        if why is None:
             from repro.kernels.flash_attention import flash_attention
 
             if "-" in impl:
@@ -272,6 +283,17 @@ def simplex_attention(
                 q, k, v, kind=kind, block_q=dec.block_q,
                 block_kv=dec.block_q, scale=scale, interpret=interpret,
             )
+        if impl != "auto":
+            raise ValueError(
+                f"attention impl {impl!r} forces the flash kernel, which "
+                f"cannot map q{tuple(q.shape)} k{tuple(k.shape)} "
+                f"v{tuple(v.shape)}: {why}"
+            )
+        warnings.warn(
+            f"simplex_attention: chunked XLA attention for "
+            f"q{tuple(q.shape)}: {why}",
+            stacklevel=2,
+        )
     return chunked_causal_attention(
         q, k, v, chunk=chunk, schedule=schedule, scale=scale
     )
@@ -304,7 +326,6 @@ def sharded_causal_attention(q, k, v, cfg, mesh):
         # no TP: attention is batch-local; shard_map over ALL axes on
         # batch keeps the folded tile walk collective-free.
         import numpy as np
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         axes = tuple(mesh.axis_names)
@@ -314,7 +335,7 @@ def sharded_causal_attention(q, k, v, cfg, mesh):
                 q, k, v, chunk=cfg.attention_chunk,
                 schedule=cfg.attention_schedule,
             )
-        f = shard_map(
+        f = jax.shard_map(
             lambda ql, kl, vl: chunked_causal_attention(
                 ql, kl, vl, chunk=cfg.attention_chunk,
                 schedule=cfg.attention_schedule,
@@ -322,7 +343,7 @@ def sharded_causal_attention(q, k, v, cfg, mesh):
             mesh=mesh,
             in_specs=(P(axes), P(axes), P(axes)),
             out_specs=P(axes),
-            check_rep=False,
+            check_vma=False,
         )
         return f(q, k, v)
     msize = mesh.shape["model"]
@@ -335,7 +356,6 @@ def sharded_causal_attention(q, k, v, cfg, mesh):
             q, k, v, chunk=cfg.attention_chunk, schedule=cfg.attention_schedule
         )
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
@@ -353,7 +373,7 @@ def sharded_causal_attention(q, k, v, cfg, mesh):
             schedule=cfg.attention_schedule,
         )
 
-    f = shard_map(
+    f = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -362,7 +382,7 @@ def sharded_causal_attention(q, k, v, cfg, mesh):
             P(bspec, None, None, None),
         ),
         out_specs=P(bspec, "model", None, None),
-        check_rep=False,
+        check_vma=False,
     )
     return f(q, k, v)
 

@@ -132,7 +132,6 @@ def moe_apply(p, cfg, x, mesh=None):
         out, aux = _moe_ep(p, cfg, x, mesh)
     else:
         import numpy as np
-        from jax.experimental.shard_map import shard_map
 
         tp = getattr(cfg, "tp_size", 16) > 1
         dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
@@ -148,7 +147,7 @@ def moe_apply(p, cfg, x, mesh=None):
             o, a = local(xl, r, w1, w3, w2, psum_ax)
             return o, jax.lax.pmean(a, dp)
 
-        f = shard_map(
+        f = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(
@@ -159,7 +158,7 @@ def moe_apply(p, cfg, x, mesh=None):
                 P(None, ffspec, None),
             ),
             out_specs=(P(bspec, None, None), P()),
-            check_rep=False,
+            check_vma=False,
         )
         out, aux = f(x, p["router"], p["w1"], p["w3"], p["w2"])
 
@@ -178,7 +177,6 @@ def _moe_ep(p, cfg, x, mesh):
     ~top_k*tokens*d per layer instead of one token psum.
     """
     import numpy as np
-    from jax.experimental.shard_map import shard_map
 
     mc = cfg.moe
     b, s, d = x.shape
@@ -234,7 +232,7 @@ def _moe_ep(p, cfg, x, mesh):
         aux = mc.n_experts * jnp.sum(frac_tokens * jnp.mean(probs, axis=0))
         return out, jax.lax.pmean(aux, dp)
 
-    f = shard_map(
+    f = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -245,6 +243,6 @@ def _moe_ep(p, cfg, x, mesh):
             P("model", None, None),
         ),
         out_specs=(P(bspec, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return f(x, p["router"], p["w1"], p["w3"], p["w2"])

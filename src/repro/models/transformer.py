@@ -169,7 +169,11 @@ def init_block_cache(cfg, spec, batch, seq, dtype, cross: bool = False):
 
 
 def stack_init(key, cfg, specs, n_periods, dtype, cross: bool = False):
-    """Stacked params: each leaf gets a leading (n_periods,) dim."""
+    """Stacked params: each leaf gets a leading (n_periods,) dim.
+
+    The periods are drawn under ``vmap`` over their keys, so each leaf
+    is built stacked: no per-period copy is ever held beside it.
+    """
 
     def one(k):
         ks = jax.random.split(k, len(specs))
@@ -178,8 +182,7 @@ def stack_init(key, cfg, specs, n_periods, dtype, cross: bool = False):
             for i, s in enumerate(specs)
         }
 
-    periods = [one(k) for k in jax.random.split(key, n_periods)]
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *periods)
+    return jax.vmap(one)(jax.random.split(key, n_periods))
 
 
 def _period_apply(cfg, specs, p, x, positions, caches, mode, mesh, enc_out,

@@ -1,9 +1,11 @@
 """Roofline analysis from compiled dry-run artifacts.
 
-Three terms (per-chip seconds), TPU v5e constants:
-  compute    = HLO_FLOPs / (chips * 197e12  bf16 FLOP/s)
-  memory     = HLO_bytes / (chips * 819e9   B/s HBM)
-  collective = wire_bytes_per_chip / 50e9   B/s per ICI link
+Three terms (per-chip seconds), at the peaks of the record's chip
+(``PEAKS``, keyed by ``device_kind``; TPU v5e: 197e12 bf16 FLOP/s,
+819e9 B/s HBM, 50e9 B/s per ICI link):
+  compute    = HLO_FLOPs / (chips * peak FLOP/s)
+  memory     = HLO_bytes / (chips * HBM B/s)
+  collective = wire_bytes_per_chip / link B/s
 
 Collective bytes are NOT in cost_analysis(); they are parsed from the
 compiled HLO text: for every all-reduce / all-gather / reduce-scatter /
@@ -30,13 +32,37 @@ from __future__ import annotations
 import json
 import os
 import re
-from typing import Dict, List
+from dataclasses import dataclass
+from typing import Dict, List, Optional
 
 import numpy as np
 
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9  # B / s / chip
-LINK_BW = 50e9  # B / s / ICI link
+
+@dataclass(frozen=True)
+class DevicePeaks:
+    """Published peaks of one chip.
+
+    Attributes:
+        flops: bf16 FLOP/s.
+        hbm_bw: HBM bytes/s.
+        link_bw: Bytes/s of one chip-to-chip (ICI) link.
+    """
+
+    flops: float
+    hbm_bw: float
+    link_bw: float
+
+
+# Keyed by ``jax.Device.device_kind``.  Source: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+# 1,600 Gbit/s of interconnect over four 50 GB/s links).
+PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(flops=197e12, hbm_bw=819e9, link_bw=50e9),
+}
+# The chip the cost model ranks for in a process with no TPU attached
+# (the CPU runs kernels only in interpret mode), and the chip the
+# dry-run records describe.
+MODEL_DEVICE_KIND = "TPU v5 lite"
 
 # -- schedule cost-model constants (per grid step / per launch) -------------
 # Scalar-unit costs of the index_map forms on the compiled path; coarse,
@@ -72,12 +98,55 @@ _COLL_OPS = (
 )
 
 __all__ = [
+    "DevicePeaks",
+    "PEAKS",
+    "MODEL_DEVICE_KIND",
+    "device_peaks",
+    "backend_peaks",
     "collective_census",
     "roofline_terms",
     "load_cells",
     "wire_bytes",
     "schedule_cost_model",
 ]
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    """The peaks of one chip kind.
+
+    Args:
+        device_kind: ``jax.Device.device_kind``, e.g. ``'TPU v5 lite'``.
+
+    Returns:
+        Its ``PEAKS`` entry.
+
+    Raises:
+        ValueError: The kind has no entry — no default stands in.
+    """
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peaks recorded for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None
+
+
+def backend_peaks() -> DevicePeaks:
+    """The peaks a cost model in this process ranks against.
+
+    With a TPU attached, its own entry (an unknown kind raises).  A
+    process with no chip runs no chip kernel to measure, so its
+    rankings model ``MODEL_DEVICE_KIND``.
+
+    Returns:
+        The ``DevicePeaks`` to model with.
+    """
+    import jax
+
+    if jax.default_backend() == "tpu":
+        return device_peaks(jax.devices()[0].device_kind)
+    return device_peaks(MODEL_DEVICE_KIND)
 
 
 def schedule_cost_model(
@@ -90,8 +159,8 @@ def schedule_cost_model(
     pieces: int = 1,
     rho: int = 8,
     dtype_bytes: int = 4,
-    hbm_bw: float = HBM_BW,
     head_dim: int = 0,
+    peaks: Optional[DevicePeaks] = None,
 ) -> float:
     """Predicted seconds per launch of one schedule kind (memory-bound).
 
@@ -130,12 +199,15 @@ def schedule_cost_model(
         pieces: Composite piece count (ignored for other kinds).
         rho: Tile side in elements.
         dtype_bytes: Element width.
-        hbm_bw: Memory bandwidth to model against.
         head_dim: Attention head dim (``attn-*`` kinds only).
+        peaks: Chip to model; None = ``backend_peaks()``.
 
     Returns:
         Predicted seconds for one launch of the full walk.
     """
+    if peaks is None:
+        peaks = backend_peaks()
+    hbm_bw = peaks.hbm_bw
     if kind.startswith("attn-"):
         d = head_dim or rho
         tile_bytes = (3 * rho * d + rho * d) * dtype_bytes  # q,k,v in + o out
@@ -145,7 +217,7 @@ def schedule_cost_model(
             # in VMEM — the structural reason flash wins on device.
             tile_bytes += 2 * rho * rho * dtype_bytes
         t_mem = steps * tile_bytes / hbm_bw
-        t_mxu = steps * 2 * (2 * rho * rho * d) / PEAK_FLOPS
+        t_mxu = steps * 2 * (2 * rho * rho * d) / peaks.flops
         per_step = {
             "attn-folded": ATTN_FOLD_SELECT_S,
             "attn-bb": PREDICATE_S,
@@ -265,13 +337,14 @@ def roofline_terms(rec: Dict) -> Dict:
     between).  Dominance uses the conservative floor.
     """
     chips = rec["n_chips"]
-    t_compute = rec["flops"] / (chips * PEAK_FLOPS)
-    t_memory_hi = rec["bytes_accessed"] / (chips * HBM_BW)
+    peaks = device_peaks(rec.get("device_kind", MODEL_DEVICE_KIND))
+    t_compute = rec["flops"] / (chips * peaks.flops)
+    t_memory_hi = rec["bytes_accessed"] / (chips * peaks.hbm_bw)
     model_size = rec.get("model_axis", 16)
     passes = (3 * rec.get("microbatches", 1)) if rec["mode"] == "train" else 1
     param_bytes = rec["params"] * 4.0  # f32 master storage
-    floor = passes * param_bytes / model_size / HBM_BW
-    t_coll = rec["collectives"]["wire_bytes_per_chip"] / LINK_BW
+    floor = passes * param_bytes / model_size / peaks.hbm_bw
+    t_coll = rec["collectives"]["wire_bytes_per_chip"] / peaks.link_bw
     terms = {
         "compute_s": t_compute,
         "memory_floor_s": floor,
@@ -282,7 +355,7 @@ def roofline_terms(rec: Dict) -> Dict:
     model_flops = factor * rec["params_active"] * rec["tokens"]
     hlo = max(rec["flops"], 1.0)
     bound = max(terms.values())
-    ideal = model_flops / (chips * PEAK_FLOPS)
+    ideal = model_flops / (chips * peaks.flops)
     return {
         **terms,
         "memory_s": t_memory_hi,  # upper bound (see docstring)

@@ -117,16 +117,33 @@ def test_simplex_attention_dispatch_bit_parity(monkeypatch):
 
 
 def test_simplex_attention_mla_shape_falls_back(monkeypatch):
-    # v_head_dim != qk head dim (MLA): flash cannot map it; the dispatch
-    # must return the chunked result, not raise.
+    # v_head_dim != qk head dim (MLA): flash cannot map it.  Under
+    # impl='auto' the dispatch returns the chunked result and says so;
+    # a forced impl='flash' raises instead of falling back.
     monkeypatch.setenv("REPRO_AUTOTUNE_DISABLE", "1")
     ks = jax.random.split(jax.random.PRNGKey(2), 3)
     q = jax.random.normal(ks[0], (1, 4, 64, 32), jnp.float32)
     k = jax.random.normal(ks[1], (1, 4, 64, 32), jnp.float32)
     v = jax.random.normal(ks[2], (1, 4, 64, 48), jnp.float32)  # dv != d
-    got = simplex_attention(q, k, v, impl="flash")
+    with pytest.warns(UserWarning, match="value head dim 48"):
+        got = simplex_attention(q, k, v, impl="auto")
     want = chunked_causal_attention(q, k, v)
     assert np.array_equal(np.asarray(got), np.asarray(want))
+    with pytest.raises(ValueError, match="value head dim 48"):
+        simplex_attention(q, k, v, impl="flash")
+
+
+@pytest.mark.parametrize("impl", ["flash", "flash-folded", "flash-bb"])
+def test_forced_flash_raises_on_unmappable_shape(monkeypatch, impl):
+    # s=36: no flash tile (128..8) divides it.  Only 'auto' may go
+    # chunked; a forced flash impl must not.
+    monkeypatch.setenv("REPRO_AUTOTUNE_DISABLE", "1")
+    q, k, v = _qkv(1, 4, 1, 36, 16)
+    with pytest.raises(ValueError, match="no flash tile divides s=36"):
+        simplex_attention(q, k, v, impl=impl)
+    with pytest.warns(UserWarning, match="no flash tile divides s=36"):
+        got = simplex_attention(q, k, v, impl="auto")
+    assert got.shape == q.shape
 
 
 def test_simplex_attention_rejects_unknown_impl():

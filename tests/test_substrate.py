@@ -153,3 +153,13 @@ def test_roofline_terms_shape():
     assert t["dominant"] in ("compute", "memory", "collective")
     assert t["compute_s"] == pytest.approx(1e18 / (256 * 197e12))
     assert 0 < t["useful_ratio"] <= 10
+
+
+def test_device_peaks_table():
+    from repro.roofline.analysis import PEAKS, backend_peaks, device_peaks
+
+    v5e = device_peaks("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(ValueError, match="no peaks recorded"):
+        device_peaks("TPU v99")
+    assert backend_peaks() in PEAKS.values()  # CPU: the modeled chip
