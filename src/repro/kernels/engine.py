@@ -548,10 +548,12 @@ def _launch_domain(kernel: "SimplexKernel", body: KernelBody, x):
     boundary = body.boundary(m)
 
     # trash tile appended along axis 0: invalid grid steps park there.
-    padded = jnp.concatenate(
-        [jnp.asarray(seed), jnp.zeros((rho,) + seed.shape[1:], seed.dtype)],
-        axis=0,
-    )
+    with jax.named_scope("engine.pad"):
+        padded = jnp.concatenate(
+            [jnp.asarray(seed),
+             jnp.zeros((rho,) + seed.shape[1:], seed.dtype)],
+            axis=0,
+        )
     dtype = padded.dtype
 
     for sched in _launch_plan(m, nb, kernel.kind, kernel.split,
@@ -613,15 +615,23 @@ def _launch_domain(kernel: "SimplexKernel", body: KernelBody, x):
         block_bytes = (len(shifts) + 1) * rho**m * dtype.itemsize + sum(
             rho * e.shape[1] * e.dtype.itemsize for e in extras
         )
-        padded = pallas_launch(
-            _kernel_fn,
-            interpret=interpret,
-            out_shape=jax.ShapeDtypeStruct(padded.shape, dtype),
-            grid_spec=grid_spec,
-            input_output_aliases={alias_src: 0},
-            **_compiler_params(interpret, block_bytes, rho**m * 4),
-        )(*args, *([padded] * len(shifts)), *extras)
-    return padded[:n]
+        with jax.named_scope("engine.kernel"):
+            padded = pallas_launch(
+                _kernel_fn,
+                interpret=interpret,
+                out_shape=jax.ShapeDtypeStruct(padded.shape, dtype),
+                grid_spec=grid_spec,
+                input_output_aliases={alias_src: 0},
+                name=_kernel_name(body.name, sched.kind),
+                **_compiler_params(interpret, block_bytes, rho**m * 4),
+            )(*args, *([padded] * len(shifts)), *extras)
+    with jax.named_scope("engine.unpad"):
+        return padded[:n]
+
+
+def _kernel_name(body: str, kind: str) -> str:
+    """The launch's stable kernel name, ``engine_<body>_<kind>``."""
+    return f"engine_{body}_{kind}".replace("-", "_")
 
 
 def _compiler_params(interpret: bool, block_bytes: int,
@@ -866,13 +876,16 @@ class MapBody(KernelBody):
             table, (padded // chunk,), [],
             pl.BlockSpec((chunk, width), _out_map),
         )
-        out = pallas_launch(
-            _kernel_fn,
-            interpret=interpret,
-            out_shape=jax.ShapeDtypeStruct((padded, width), jnp.int32),
-            grid_spec=grid_spec,
-        )(*args)
-        return out[:steps]
+        with jax.named_scope("engine.kernel"):
+            out = pallas_launch(
+                _kernel_fn,
+                interpret=interpret,
+                out_shape=jax.ShapeDtypeStruct((padded, width), jnp.int32),
+                grid_spec=grid_spec,
+                name=_kernel_name(self.name, sched.kind),
+            )(*args)
+        with jax.named_scope("engine.unpad"):
+            return out[:steps]
 
     def xla_executor(self, kernel: "SimplexKernel", nb: int):
         """The walk evaluated as ONE jit program (compiled.py)."""

@@ -463,5 +463,6 @@ def _flash_launch(kind, block_q, block_kv, scale, interpret, q, k, v,
             pltpu.VMEM((block_q, d), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
+        name=f"flash_{kind}",
     )(qr, kr, vr, *extra_in)
     return out.reshape(b, hq, s, d)

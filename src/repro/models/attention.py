@@ -446,38 +446,44 @@ def attn_apply(
     b, s, d = x.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     dt = x.dtype
-    q = jnp.dot(x, p["wq"].astype(dt)).reshape(b, s, hq, hd).transpose(0, 2, 1, 3)
+    with jax.named_scope("layer.attn_qkv"):
+        q = jnp.dot(x, p["wq"].astype(dt)).reshape(b, s, hq, hd)
+        q = q.transpose(0, 2, 1, 3)
+        if cross_kv is None:
+            k = jnp.dot(x, p["wk"].astype(dt)).reshape(b, s, hkv, hd)
+            v = jnp.dot(x, p["wv"].astype(dt)).reshape(b, s, hkv, hd)
+            k = k.transpose(0, 2, 1, 3)
+            v = v.transpose(0, 2, 1, 3)
     if cross_kv is None:
-        k = jnp.dot(x, p["wk"].astype(dt)).reshape(b, s, hkv, hd)
-        v = jnp.dot(x, p["wv"].astype(dt)).reshape(b, s, hkv, hd)
-        k = k.transpose(0, 2, 1, 3)
-        v = v.transpose(0, 2, 1, 3)
-        if cfg.mrope_sections is not None and positions3 is not None:
-            from .layers import mrope
+        with jax.named_scope("layer.rope"):
+            if cfg.mrope_sections is not None and positions3 is not None:
+                from .layers import mrope
 
-            q = mrope(q, positions3, cfg.mrope_sections, cfg.rope_theta)
-            k = mrope(k, positions3, cfg.mrope_sections, cfg.rope_theta)
-        else:
-            q = rope(q, positions, cfg.rope_theta)
-            k = rope(k, positions, cfg.rope_theta)
+                q = mrope(q, positions3, cfg.mrope_sections, cfg.rope_theta)
+                k = mrope(k, positions3, cfg.mrope_sections, cfg.rope_theta)
+            else:
+                q = rope(q, positions, cfg.rope_theta)
+                k = rope(k, positions, cfg.rope_theta)
     else:
         k, v = cross_kv
 
     new_cache = None
-    if mode == "decode" and cross_kv is None:
-        kc, vc = cache
-        o = decode_attention(q, kc, vc, k, v)
-        new_cache = (kc, vc, k, v)  # caller appends (ring/position update)
-    elif bidirectional or cross_kv is not None:
-        o = full_attention(q, k, v, chunk=cfg.attention_chunk)
-        if mode == "prefill" and cross_kv is None:
+    with jax.named_scope("layer.attn_core"):
+        if mode == "decode" and cross_kv is None:
+            kc, vc = cache
+            o = decode_attention(q, kc, vc, k, v)
+        elif bidirectional or cross_kv is not None:
+            o = full_attention(q, k, v, chunk=cfg.attention_chunk)
+        else:
+            o = sharded_causal_attention(q, k, v, cfg, mesh)
+    with jax.named_scope("layer.kv_cache"):
+        if mode == "decode" and cross_kv is None:
+            new_cache = (kc, vc, k, v)  # caller appends (ring/position update)
+        elif mode == "prefill" and cross_kv is None:
             new_cache = (k, v)
-    else:
-        o = sharded_causal_attention(q, k, v, cfg, mesh)
-        if mode == "prefill":
-            new_cache = (k, v)
-    o = o.transpose(0, 2, 1, 3).reshape(b, s, hq * hd)
-    return jnp.dot(o, p["wo"].astype(dt)), new_cache
+    with jax.named_scope("layer.attn_out"):
+        o = o.transpose(0, 2, 1, 3).reshape(b, s, hq * hd)
+        return jnp.dot(o, p["wo"].astype(dt)), new_cache
 
 
 def init_kv_cache(cfg, batch, seq, dtype):

@@ -87,7 +87,8 @@ class Model:
         """Returns (embeds (B,S,d), positions (B,S), positions3 or None)."""
         cfg = self.cfg
         tokens = batch["tokens"]
-        x = embed(params["embed"], tokens, self.adtype)
+        with jax.named_scope("model.embed"):
+            x = embed(params["embed"], tokens, self.adtype)
         b = tokens.shape[0]
         if cfg.n_patches and "patches" in batch:
             patches = batch["patches"].astype(self.adtype)  # (B,P,d)
@@ -145,7 +146,8 @@ class Model:
         )
         new_caches["stack"] = sc
         aux = aux + a
-        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        with jax.named_scope("model.lm_head"):
+            x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
         return x, new_caches, aux
 
     def _encode(self, params, src_embeds):
@@ -161,10 +163,12 @@ class Model:
 
     def _logits(self, params, x):
         cfg = self.cfg
-        w = (
-            params["embed"]["e"].T if cfg.tie_embeddings else params["unembed"]
-        ).astype(self.adtype)
-        return jnp.dot(x, w)
+        with jax.named_scope("model.lm_head"):
+            w = (
+                params["embed"]["e"].T if cfg.tie_embeddings
+                else params["unembed"]
+            ).astype(self.adtype)
+            return jnp.dot(x, w)
 
     # ------------------------------------------------------------------ loss
 
@@ -223,7 +227,8 @@ class Model:
     def decode(self, params, caches, batch, mesh=None):
         """One token against full caches.  batch['tokens']: (B, 1);
         batch['pos']: (B,) absolute position of the new token."""
-        x = embed(params["embed"], batch["tokens"], self.adtype)
+        with jax.named_scope("model.embed"):
+            x = embed(params["embed"], batch["tokens"], self.adtype)
         b = x.shape[0]
         positions = batch["pos"][:, None]
         pos3 = None

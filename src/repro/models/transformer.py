@@ -79,7 +79,8 @@ def block_apply(
 ):
     """Returns (x, new_cache, aux_loss)."""
     aux = jnp.zeros((), jnp.float32)
-    h = rmsnorm(p["norm1"], x, cfg.norm_eps)
+    with jax.named_scope("layer.norm"):
+        h = rmsnorm(p["norm1"], x, cfg.norm_eps)
     mixer_cache = cache.get("mixer") if cache else None
     if spec.mixer == "attn":
         if cfg.attention == "mla":
@@ -111,7 +112,8 @@ def block_apply(
         new_cache["mixer"] = new_mixer
 
     if "cross" in p and enc_out is not None or cross_cache is not None:
-        hx = rmsnorm(p["norm_x"], x, cfg.norm_eps)
+        with jax.named_scope("layer.norm"):
+            hx = rmsnorm(p["norm_x"], x, cfg.norm_eps)
         if cross_cache is not None:
             kv = cross_cache
         else:
@@ -133,10 +135,14 @@ def block_apply(
         )
         x = x + o
 
-    if spec.ffn == "dense":
-        x = x + swiglu(p["ffn"], rmsnorm(p["norm2"], x, cfg.norm_eps))
-    elif spec.ffn == "moe":
-        o, aux = moe_apply(p["ffn"], cfg, rmsnorm(p["norm2"], x, cfg.norm_eps), mesh)
+    if spec.ffn in ("dense", "moe"):
+        with jax.named_scope("layer.norm"):
+            h = rmsnorm(p["norm2"], x, cfg.norm_eps)
+        with jax.named_scope("layer.ffn"):
+            if spec.ffn == "dense":
+                o = swiglu(p["ffn"], h)
+            else:
+                o, aux = moe_apply(p["ffn"], cfg, h, mesh)
         x = x + o
     return x, new_cache, aux
 

@@ -7,11 +7,16 @@ Every kernel here must lower to a ``tpu_custom_call`` at its real
 width.  The topology is described inside a fixture, never at import,
 so every test worker collects the same tests.  Each test steers the
 kernel to the compiled path itself (``interpret=False`` and an explicit
-tile), since the process's own backend is the CPU.
+tile), since the process's own backend is the CPU.  The last tests read
+the compiled text's ``op_name`` metadata: every op of the prefill step
+and of an engine launch sits in the named scope of its program layer.
 """
 
 import functools
 import os
+import re
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +26,9 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import engine
 from repro.kernels.flash_attention import flash_attention
 from repro.models.attention import decode_attention
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+from bench.core.scopes import UNSCOPED, op_scopes  # noqa: E402
 
 # yi-6b attention widths: 32 q / 4 kv heads of 128, batch 4, prompt 2048.
 B, HQ, HKV, S, D = 4, 32, 4, 2048, 128
@@ -112,3 +120,80 @@ def test_engine_accum_m3_compiles(one_chip):
                            interpret=False)
     text = _compile_text(fn, one_chip, ((512, 512, 512), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------------
+# named scopes: every op of a compiled step carries its program layer in its
+# op_name metadata; the chip benchmark charges its device time there
+# (bench/core/scopes.py), so a matmul outside the matmul scopes, or an engine
+# copy outside engine.pad / engine.unpad, would land in the wrong layer.
+# ---------------------------------------------------------------------------
+
+MATMUL_SCOPES = {"layer.ffn", "layer.attn_qkv", "layer.attn_out", "model.lm_head"}
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%(\S+) = .*?\s([a-z][\w\-]*)\(")
+
+
+def _opcodes(text):
+    """``{instruction name: opcode}`` of every instruction of ``text``."""
+    return {m.group(1): m.group(2)
+            for m in map(_INSTR.match, text.splitlines()) if m}
+
+
+def _kernels(text):
+    """Names of the Pallas (Mosaic) custom calls of ``text``."""
+    return [m.group(1) for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            and (m := _INSTR.match(line))]
+
+
+def test_prefill_ops_are_scoped(one_chip, monkeypatch):
+    from repro.configs.base import get_config
+    from repro.models.model import Model
+
+    monkeypatch.setenv("REPRO_INTERPRET", "0")  # flash on its compiled path
+    cfg = get_config("yi-6b").replace(
+        n_layers=2, d_model=256, n_heads=2, n_kv_heads=1, head_dim=128,
+        d_ff=512, vocab=512, param_dtype="bfloat16", act_dtype="bfloat16",
+        remat="none")
+    model = Model(cfg)
+
+    def prefill_step(params, tokens):
+        logits, caches = model.prefill(params, {"tokens": tokens})
+        return jnp.argmax(logits[:, -1], -1), caches
+
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((1, 256), jnp.int32, sharding=one_chip)
+    text = jax.jit(prefill_step).lower(params, tokens).compile().as_text()
+    scopes, opcodes = op_scopes(text), _opcodes(text)
+    matmuls = {n: scopes[n] for n, op in opcodes.items()
+               if op in ("dot", "convolution")}
+    assert set(matmuls.values()) <= MATMUL_SCOPES, matmuls
+    # the head at one position is a multiply-reduce, not a dot
+    assert {"layer.ffn", "layer.attn_qkv", "layer.attn_out"} <= set(
+        matmuls.values())
+    kernels = _kernels(text)
+    assert [n.split(".")[0] for n in kernels] == ["flash_folded"]
+    assert {scopes[n] for n in kernels} == {"layer.attn_core"}
+    for scope in ("layer.norm", "layer.rope", "model.embed", "model.lm_head"):
+        assert scope in scopes.values()
+
+
+@pytest.mark.parametrize("body", ["accum", "ca"])
+def test_engine_copies_and_kernel_are_scoped(one_chip, body):
+    launch = getattr(engine, body)
+    fn = functools.partial(launch, rho=128, kind="hmap", interpret=False)
+    text = _compile_text(fn, one_chip, ((256, 256), jnp.int32))
+    scopes, opcodes = op_scopes(text), _opcodes(text)
+    by_op = {}
+    for name, op in opcodes.items():
+        by_op.setdefault(op, set()).add(scopes[name])
+    assert by_op["pad"] == {"engine.pad"}
+    assert by_op["slice"] == {"engine.unpad"}
+    # CA's halo reads the state it writes: aliasing it forces a copy
+    assert by_op.get("copy", set()) == ({"engine.pad"} if body == "ca" else set())
+    assert _kernels(text) == [f"engine_{body}_hmap.1"]
+    assert scopes[f"engine_{body}_hmap.1"] == "engine.kernel"
+    assert UNSCOPED not in {scopes[n] for n, op in opcodes.items()
+                            if op in ("pad", "slice", "copy", "custom-call")}
