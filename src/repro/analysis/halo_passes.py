@@ -16,7 +16,10 @@ then replays every fetch map over real schedule walks (DESIGN.md §9):
 * for every fetched offset, the evaluated index map must equal the
   boundary-correct neighbour (wrap mod nb under ``'periodic'``, clip +
   trash-park under ``'free'``) and stay inside ``[0, nb]`` — the range
-  Pallas can actually address after trash-tile padding.
+  Pallas can actually address after trash-tile padding.  A walk the
+  engine launches without the trash row (``core.schedule.
+  every_step_valid``) must have no invalid step and stay inside
+  ``[0, nb-1]``.
 
 All checks replay index maps with numpy step enumerations — no Pallas
 launch.
@@ -38,13 +41,15 @@ __all__ = [
 
 # (m, nb, kind) combos the registered pass replays per body: a pow2
 # multi-axis walk, the bounding-box walk (invalid steps exercise the
-# trash parking), and a non-pow2 composite walk at m=3.
+# trash parking), a non-pow2 composite walk at m=3, and an exact walk
+# at m=3 (free boundary, no trash row).
 HALO_MN: Tuple[Tuple[int, int, str], ...] = (
     (2, 4, "hmap"),
     (2, 4, "bb"),
     (3, 4, "hmap"),
     (3, 4, "bb"),
     (3, 3, "composite"),
+    (3, 4, "table"),
 )
 
 
@@ -61,7 +66,11 @@ def check_body_halo(body, m: int, nb: int, kind: str) -> List[Finding]:
         Findings for declaration/fetch mismatches, boundary-handling
         drift, and out-of-range fetches; empty when conformant.
     """
-    from repro.core.schedule import SimplexSchedule, resolve_kind
+    from repro.core.schedule import (
+        SimplexSchedule,
+        every_step_valid,
+        resolve_kind,
+    )
     from repro.kernels.engine import (
         get_body,
         launch_shifts,
@@ -95,8 +104,16 @@ def check_body_halo(body, m: int, nb: int, kind: str) -> List[Finding]:
     coords, valid = eval_schedule_map(sched)
     blocks = tuple(c for c in coords[::-1])  # array-axis order
     boundary = body.boundary(m)
+    trash = not every_step_valid(sched)
+    if not trash and not valid.all():
+        return [Finding(
+            "halo-conformance", where, 0,
+            f"grid step {int(np.nonzero(~valid)[0][0])} is invalid, but "
+            "the walk is launched without a trash row (every_step_valid)",
+        )]
+    top = nb if trash else nb - 1  # the last block row Pallas can address
     for d in sorted(fetched):
-        tr = shift_block_transform(d, nb, boundary)
+        tr = shift_block_transform(d, nb, boundary, trash)
         got = [
             np.asarray(b).astype(np.int64)
             for b in tr(blocks, coords, valid)
@@ -109,7 +126,8 @@ def check_body_halo(body, m: int, nb: int, kind: str) -> List[Finding]:
             want = [
                 np.clip(blocks[j] + d[j], 0, nb - 1) for j in range(m)
             ]
-            want[0] = np.where(valid, want[0], nb)
+            if trash:
+                want[0] = np.where(valid, want[0], nb)
         for j in range(m):
             bad = np.nonzero(got[j] != want[j])[0]
             if bad.size:
@@ -123,14 +141,14 @@ def check_body_halo(body, m: int, nb: int, kind: str) -> List[Finding]:
                 ))
                 break
         lo_ok = all((g >= 0).all() for g in got)
-        hi_ok = (got[0] <= nb).all() and all(
+        hi_ok = (got[0] <= top).all() and all(
             (g <= nb - 1).all() for g in got[1:]
         )
         if not (lo_ok and hi_ok):
             out.append(Finding(
                 "halo-conformance", where, 0,
                 f"fetch map for offset {d} addresses a block outside "
-                f"[0, {nb}] — unmapped memory even with the trash row",
+                f"[0, {top}] — unmapped memory for this launch",
             ))
     return out
 

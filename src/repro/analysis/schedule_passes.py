@@ -6,10 +6,11 @@ two schedule-level facts the engine otherwise only observes at runtime:
 * **bijectivity** — the valid steps of a walk cover the blocked simplex
   exactly once each (no hole, no duplicate) with every coordinate in
   range; and
-* **write-race freedom** — after the engine's output transform (clip +
-  trash-tile parking, ``kernels.engine.out_block_transform``) no two
-  grid steps write the same live output block, and every invalid step
-  parks at the trash row.
+* **write-race freedom** — after the engine's output transform (clip,
+  plus trash-tile parking where the walk can hold an invalid step,
+  ``kernels.engine.out_block_transform``) no two grid steps write the
+  same live output block, and every invalid step parks at the trash
+  row; a walk launched without one has no invalid step.
 
 Both are decidable by replaying ``SimplexSchedule.map`` over the full
 step enumeration (``core.schedule.step_grid_indices``) on small (m, n)
@@ -146,13 +147,15 @@ def check_schedule_race(sched, m: int, n: int,
     """No two grid steps may write the same live output block.
 
     Applies the engine's actual output index-map transform
-    (``kernels.engine.out_block_transform``: clip to range, park
-    invalid steps at the trash row) to every step of the walk, then
-    checks (a) valid steps land on pairwise-distinct blocks — two steps
-    sharing an output block is the λ-map overlap race, the launch-order-
-    dependent write the triangular-map line of work guards against —
-    and (b) invalid steps all park at the trash row, never on a live
-    block.
+    (``kernels.engine.out_block_transform``: clip to range, and park
+    invalid steps at the trash row where the launch has one — that is,
+    unless ``core.schedule.every_step_valid``) to every step of the
+    walk, then checks (a) valid steps land on pairwise-distinct blocks —
+    two steps sharing an output block is the λ-map overlap race, the
+    launch-order-dependent write the triangular-map line of work guards
+    against — and (b) invalid steps all park at the trash row, never on
+    a live block.  A walk launched without the trash row must have no
+    invalid step and every block index in ``[0, n-1]``.
 
     Args:
         sched: The schedule (or shard/piece view) to verify.
@@ -161,17 +164,37 @@ def check_schedule_race(sched, m: int, n: int,
         pass_name: Name stamped on the findings.
 
     Returns:
-        Findings for racing step pairs and mis-parked invalid steps.
+        Findings for racing step pairs, mis-parked invalid steps, and
+        invalid or out-of-range steps of a walk with no trash row.
     """
+    from repro.core.schedule import every_step_valid
     from repro.kernels.engine import out_block_transform
 
     coords, valid = eval_schedule_map(sched)
     where = _label(sched, m, n)
     blocks = tuple(coords[::-1])  # array-axis order
-    out_blocks = out_block_transform(n)(blocks, coords, valid)
+    trash = not every_step_valid(sched)
+    out_blocks = out_block_transform(n, trash)(blocks, coords, valid)
     cols = [np.asarray(b).astype(np.int64) for b in out_blocks]
     stack = np.stack(cols, axis=1)  # (steps, m)
     out: List[Finding] = []
+    if not trash:
+        bad = np.nonzero(~valid)[0]
+        if bad.size:
+            out.append(Finding(
+                pass_name, where, 0,
+                f"grid step {int(bad[0])} is invalid, but the walk is "
+                "launched without a trash row (every_step_valid) and "
+                "writes a live block",
+            ))
+        off = np.nonzero(((stack < 0) | (stack > n - 1)).any(axis=1))[0]
+        if off.size:
+            out.append(Finding(
+                pass_name, where, 0,
+                f"grid step {int(off[0])} writes block "
+                f"{tuple(stack[off[0]])} outside [0, {n - 1}], and the "
+                "launch has no trash row",
+            ))
     seen: Dict[tuple, int] = {}
     for step, row in enumerate(map(tuple, stack)):
         if valid[step]:
@@ -183,7 +206,7 @@ def check_schedule_race(sched, m: int, n: int,
                 ))
             else:
                 seen[row] = step
-        elif row[0] != n:
+        elif trash and row[0] != n:
             out.append(Finding(
                 pass_name, where, 0,
                 f"invalid grid step {step} writes live block {row} "
@@ -258,7 +281,12 @@ class _UnionSchedule:
     union walk can be verified with the single-schedule checkers."""
 
     def __init__(self, views):
+        from repro.core.schedule import every_step_valid
+
         self.views = views
+        # one trash row or none for a split plan's pieces; shards share
+        # their base walk's answer
+        self.exact = all(every_step_valid(v) for v in views)
         self.kind = "+".join(
             str(getattr(v, "kind", "?")) for v in views[:1]
         ) + f"[x{len(views)}]"

@@ -17,6 +17,7 @@ schedule exposes the same surface —
     .map     (*w) -> (*coords, valid); dual-backend (numpy / jax tracers)
     .table() host-side (steps, m+1) int32 walk table for inspection
     .waste() steps/useful - 1, the measured extra parallel space
+    .exact   True when no grid step is invalid (read via ``every_step_valid``)
 
 Registered kinds
 ----------------
@@ -60,12 +61,40 @@ __all__ = [
     "registered_kinds",
     "resolve_kind",
     "step_grid_indices",
+    "every_step_valid",
     "Schedule2D",
     "schedule2d_table",
     "schedule3d_table",
     "folded_causal_pairs",
     "grid_steps",
 ]
+
+
+def every_step_valid(sched) -> bool:
+    """Whether no grid step of ``sched`` can be invalid, known statically.
+
+    The engine appends a trash tile for invalid steps to park their
+    write-back on; a walk with none needs no trash tile
+    (``kernels.engine._launch_domain``).  The answer is the schedule's
+    ``exact`` attribute: ``steps == useful`` for a registered kind or a
+    composite piece (the valid steps cover all ``useful`` blocks, so a
+    walk of exactly that many steps has no room for an invalid one), the
+    base walk's answer for a ``ShardSchedule``.  A schedule surface that
+    does not state it counts as possibly invalid.
+
+    Args:
+        sched: Any schedule surface.
+
+    Returns:
+        True when every step of the walk is valid.
+
+    Example:
+        >>> every_step_valid(SimplexSchedule(2, 4, "hmap"))
+        True
+        >>> every_step_valid(SimplexSchedule(2, 4, "bb"))
+        False
+    """
+    return bool(getattr(sched, "exact", False))
 
 
 def step_grid_indices(sched) -> Tuple[np.ndarray, ...]:
@@ -327,6 +356,11 @@ class SimplexSchedule:
         """
         return self.steps / self.useful - 1.0
 
+    @property
+    def exact(self) -> bool:
+        """No grid step is invalid: the walk has no extra parallel space."""
+        return self.steps == self.useful
+
     def asymptotic_waste(self) -> Optional[float]:
         """inf-n extra-space fraction of this kind (None if unknown).
 
@@ -413,6 +447,11 @@ class _PieceSchedule:
         self.steps = piece.grid_cells
         self.useful = piece.data_cells
         self.prefetch = None
+
+    @property
+    def exact(self) -> bool:
+        """No grid step of this piece is invalid."""
+        return self.steps == self.useful
 
     def map(self, lin):
         """Piece-local linear index -> ``(*coords, valid)`` (global)."""

@@ -55,7 +55,7 @@ from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core.schedule import SimplexSchedule, resolve_kind
+from repro.core.schedule import SimplexSchedule, every_step_valid, resolve_kind
 from repro.core.simplex import simplex_volume
 
 __all__ = [
@@ -263,6 +263,11 @@ class ShardSchedule:
     def prefetch(self):
         """The base schedule's scalar-prefetch payload (table kinds)."""
         return self.base.prefetch
+
+    @property
+    def exact(self) -> bool:
+        """No grid step is invalid: a shard of an exact base walk."""
+        return every_step_valid(self.base)
 
     def _global(self, lin):
         """Shard-local linear index -> base step-order index."""

@@ -17,10 +17,12 @@ established, dimension-generically:
   linear grids (everything else) through the same index-map builder;
   table-driven kinds ship their payload via
   ``PrefetchScalarGridSpec``.
-* **Trash tile** — the domain array is padded by one tile row along
-  axis 0 and invalid grid steps park there, so Pallas' end-of-step
-  block flush never clobbers live data; in-place semantics come from
-  input/output aliasing of the body's *seed* array.
+* **Trash tile** — when the launch plan can hold an invalid grid step,
+  the domain array is padded by one tile row along axis 0 and invalid
+  steps park there, so Pallas' end-of-step block flush never clobbers
+  live data; a plan whose every step is valid launches on the seed
+  array itself.  In-place semantics come from input/output aliasing of
+  the body's *seed* array.
 * **3^m halo subsystem** — bodies that declare ``halo = True`` receive
   the 3^m tiles fetched by shifted input refs (the standard Pallas
   stencil pattern — no element-offset reads on TPU), each masked by
@@ -67,7 +69,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.schedule import SimplexSchedule, resolve_kind
+from repro.core.schedule import SimplexSchedule, every_step_valid, resolve_kind
 
 from .policy import (
     aligned_rho,
@@ -207,17 +209,21 @@ def launch_shifts(body: "KernelBody", m: int) -> Tuple[Tuple[int, ...], ...]:
     return halo_shifts(m) if body.halo else ((0,) * m,)
 
 
-def out_block_transform(nb: int) -> Callable:
-    """The engine's output index-map transform: clip + trash-tile park.
+def out_block_transform(nb: int, trash: bool) -> Callable:
+    """The engine's output index-map transform: clip, and trash-tile park.
 
-    Valid grid steps write their (clipped) block; invalid steps park at
-    the trash row ``nb`` appended along axis 0, so Pallas' end-of-step
-    flush never clobbers live data.  Shared by ``_launch_domain`` and
+    Valid grid steps write their (clipped) block.  The trash row ``nb``
+    exists only where the launch plan can hold an invalid step (not
+    ``core.schedule.every_step_valid``): it is appended along axis 0 and
+    invalid steps park there, so Pallas' end-of-step flush never
+    clobbers live data.  A plan whose every step is valid has no trash
+    row, and the transform only clips.  Shared by ``_launch_domain`` and
     the write-race pass in ``repro.analysis`` so the analyzer verifies
     the exact transform the launcher uses (DESIGN.md §9).
 
     Args:
         nb: Tile count per side (trash row index along axis 0).
+        trash: Whether the launch has the trash row.
 
     Returns:
         ``transform(blocks, coords, valid) -> block index tuple`` in
@@ -226,24 +232,28 @@ def out_block_transform(nb: int) -> Callable:
 
     def _t(blocks, coords, valid):
         clipped = tuple(jnp.clip(b, 0, nb - 1) for b in blocks)
+        if not trash:
+            return clipped
         return (jnp.where(valid, clipped[0], nb),) + clipped[1:]
 
     return _t
 
 
-def shift_block_transform(d: Tuple[int, ...], nb: int,
-                          boundary: str) -> Callable:
+def shift_block_transform(d: Tuple[int, ...], nb: int, boundary: str,
+                          trash: bool) -> Callable:
     """The engine's input index-map transform for stencil offset ``d``.
 
     ``'periodic'`` wraps block coordinates mod nb (the 2-simplex CA
-    convention); ``'free'`` clamps at the domain edge and parks invalid
-    steps at the trash row (the m >= 3 convention) — clamp duplicates
-    are masked inert by true coordinates at assembly time.
+    convention); ``'free'`` clamps at the domain edge and, where the
+    launch has a trash row, parks invalid steps there (the m >= 3
+    convention) — clamp duplicates are masked inert by true coordinates
+    at assembly time.
 
     Args:
         d: Block offset, one entry per array axis.
         nb: Tile count per side.
         boundary: ``'periodic'`` or ``'free'``.
+        trash: Whether the launch has the trash row (``out_block_transform``).
 
     Returns:
         ``transform(blocks, coords, valid) -> block index tuple``.
@@ -255,6 +265,8 @@ def shift_block_transform(d: Tuple[int, ...], nb: int,
         shifted = tuple(
             jnp.clip(b + dj, 0, nb - 1) for b, dj in zip(blocks, d)
         )
+        if not trash:
+            return shifted
         return (jnp.where(valid, shifted[0], nb),) + shifted[1:]
 
     return _t
@@ -532,8 +544,8 @@ def get_body(body) -> KernelBody:
 
 
 def _launch_domain(kernel: "SimplexKernel", body: KernelBody, x):
-    """One launch per plan entry: seed/trash-tile padding, index maps,
-    halo assembly, aliased output — the engine core."""
+    """One launch per plan entry: trash-tile padding where a step can be
+    invalid, index maps, halo assembly, aliased output — the engine core."""
     m, rho = kernel.m, kernel.rho
     seed, n = body.seed(x, m)
     if n % rho != 0:
@@ -547,20 +559,24 @@ def _launch_domain(kernel: "SimplexKernel", body: KernelBody, x):
     centre_idx = shifts.index((0,) * m)
     boundary = body.boundary(m)
 
-    # trash tile appended along axis 0: invalid grid steps park there.
-    with jax.named_scope("engine.pad"):
-        padded = jnp.concatenate(
-            [jnp.asarray(seed),
-             jnp.zeros((rho,) + seed.shape[1:], seed.dtype)],
-            axis=0,
-        )
-    dtype = padded.dtype
+    plan = _launch_plan(m, nb, kernel.kind, kernel.split,
+                        body.element_local and not body.halo,
+                        schedule=kernel.schedule)
+    # trash tile appended along axis 0 where a grid step can be invalid:
+    # such steps park there.  Otherwise the kernel writes the seed in place.
+    trash = not all(every_step_valid(sched) for sched in plan)
+    state = jnp.asarray(seed)
+    if trash:
+        with jax.named_scope("engine.pad"):
+            state = jnp.concatenate(
+                [state, jnp.zeros((rho,) + seed.shape[1:], seed.dtype)],
+                axis=0,
+            )
+    dtype = state.dtype
+    _out_transform = out_block_transform(nb, trash)
 
-    for sched in _launch_plan(m, nb, kernel.kind, kernel.split,
-                              body.element_local and not body.halo,
-                              schedule=kernel.schedule):
+    for sched in plan:
         fn, table = sched.map, sched.prefetch
-        _out_transform = out_block_transform(nb)
 
         in_specs = [
             pl.BlockSpec(
@@ -568,7 +584,7 @@ def _launch_domain(kernel: "SimplexKernel", body: KernelBody, x):
                 _make_index_map(
                     fn,
                     _out_transform if d == (0,) * m
-                    else shift_block_transform(d, nb, boundary),
+                    else shift_block_transform(d, nb, boundary, trash),
                 ),
             )
             for d in shifts
@@ -616,17 +632,19 @@ def _launch_domain(kernel: "SimplexKernel", body: KernelBody, x):
             rho * e.shape[1] * e.dtype.itemsize for e in extras
         )
         with jax.named_scope("engine.kernel"):
-            padded = pallas_launch(
+            state = pallas_launch(
                 _kernel_fn,
                 interpret=interpret,
-                out_shape=jax.ShapeDtypeStruct(padded.shape, dtype),
+                out_shape=jax.ShapeDtypeStruct(state.shape, dtype),
                 grid_spec=grid_spec,
                 input_output_aliases={alias_src: 0},
                 name=_kernel_name(body.name, sched.kind),
                 **_compiler_params(interpret, block_bytes, rho**m * 4),
-            )(*args, *([padded] * len(shifts)), *extras)
+            )(*args, *([state] * len(shifts)), *extras)
+    if not trash:
+        return state
     with jax.named_scope("engine.unpad"):
-        return padded[:n]
+        return state[:n]
 
 
 def _kernel_name(body: str, kind: str) -> str:
@@ -924,7 +942,7 @@ class SimplexKernel:
 
     ``SimplexKernel(body, m)`` resolves the body from the registry and
     launches it over any ``SimplexSchedule`` — the engine handles grid
-    shape, scalar prefetch, trash-tile parking, halo assembly,
+    shape, scalar prefetch, trash-tile parking of invalid steps, halo assembly,
     execution policy, and composite launch splitting uniformly
     (DESIGN.md §2.3).
 

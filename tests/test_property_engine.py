@@ -97,12 +97,16 @@ def test_ca_md_permutation_consistent(m, seed, data):
     perm = data.draw(
         st.sampled_from(list(itertools.permutations(range(m)))[1:])
     )
-    s = _state(seed, m, n)
-    # symmetric input -> symmetric output (integer CA: exact)
-    s_sym = jnp.asarray(
-        np.minimum(np.asarray(s), np.asarray(s).transpose(perm))
-    )
-    out = np.asarray(E.ca_md(s_sym, rho=2, kind="table"))
+    s = np.asarray(_state(seed, m, n))
+    # symmetric input -> symmetric output (integer CA: exact). The min
+    # over every power of perm is invariant under perm; the min with
+    # perm alone is not where perm is no involution (a 3-cycle).
+    s_sym, power = s, perm
+    while power != tuple(range(m)):
+        s_sym = np.minimum(s_sym, s.transpose(power))
+        power = tuple(power[i] for i in perm)
+    assert np.array_equal(s_sym, s_sym.transpose(perm))
+    out = np.asarray(E.ca_md(jnp.asarray(s_sym), rho=2, kind="table"))
     assert np.array_equal(out, out.transpose(perm))
 
 

@@ -180,6 +180,23 @@ def test_write_race_catches_duplicate_output_block():
     assert not check_schedule_race(base, 2, 4)
 
 
+def test_write_race_catches_invalid_step_without_trash_row():
+    base = SimplexSchedule(2, 4, "hmap")
+    i, _ = _first_valid_pair(base)
+
+    def mutate(coords, valid):
+        valid[i] = False
+
+    parked = _Corrupted(base, mutate)  # states no exactness: a trash row
+    assert not check_schedule_race(parked, 2, 4)
+    claimed = _Corrupted(base, mutate)
+    claimed.exact = True  # launched with no trash row to park on
+    findings = check_schedule_race(claimed, 2, 4)
+    assert findings
+    assert all("without a trash row" in f.message for f in findings)
+    assert not check_schedule_race(base, 2, 4)
+
+
 def test_bijectivity_catches_coverage_hole():
     base = SimplexSchedule(2, 4, "bb")
     i, _ = _first_valid_pair(base)
