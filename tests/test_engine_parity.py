@@ -17,6 +17,7 @@ schedule kind registered for the dimension; ``REPRO_PARITY_QUICK=1``
 (the CI quick mode) trims it to one pow2 size and the analytic kinds.
 """
 
+import functools
 import os
 
 import jax
@@ -188,7 +189,9 @@ def test_ca_parity(case):
     key = jax.random.PRNGKey(n * m)
     s = (jax.random.uniform(key, (n,) * m) < 0.4).astype(jnp.int32)
     s = s * R.simplex_mask(m, n, jnp.int32)
-    got = np.asarray(E.ca(s, rho=rho, kind=kind))
+    # jitted: eagerly, the m = 4 `table` launch (3^4 halo tiles) compiles
+    # about three times slower
+    got = np.asarray(jax.jit(functools.partial(E.ca, rho=rho, kind=kind))(s))
     msk = _mask(m, n)
     want = np.asarray(
         R.ca2d_step(s) if m == 2 else R.ca_md_step(s)

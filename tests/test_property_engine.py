@@ -17,8 +17,23 @@ Invariants for the O(1)-effort body registrations the engine unlocked
 Gated behind the dev-extra skip in ``tests/conftest.py`` — deterministic
 spot checks of the same invariants run unconditionally in
 ``tests/test_engine_parity.py``.
+
+What keeps the module's time fixed and small:
+
+* **one compile per shape** — every engine call goes through ``_launch``,
+  a cache of jitted launches keyed by body, kind and keyword arguments,
+  so the drawn seed changes the data a compiled launch runs on, never
+  what gets compiled (the kind-swap and permutation tests share their
+  ``table`` compiles);
+* **derandomized examples** — every ``@settings`` has
+  ``derandomize=True``, so each run draws the same examples;
+* **m = 4 CA at n = 8 only** — each m = 4 CA shape compiles a kernel of
+  3^4 halo tiles per kind, and n adds no code path;
+  ``test_engine_parity.py::test_ca_parity`` checks every m = 4 kind at
+  n = 6 and n = 8 against the oracle.
 """
 
+import functools
 import itertools
 
 import numpy as np
@@ -41,6 +56,13 @@ _KINDS = {
     4: ["hmap", "bb", "table", "composite"],
 }
 _NS = {3: [4, 8, 12], 4: [4, 6, 8]}
+_CA_NS = {3: _NS[3], 4: [8]}
+
+
+@functools.lru_cache(maxsize=None)
+def _launch(body, kind, **kw):
+    """The jitted ``E.<body>(x, rho=2, kind=kind, **kw)``, built once."""
+    return jax.jit(functools.partial(getattr(E, body), rho=2, kind=kind, **kw))
 
 
 def _points(seed, n):
@@ -53,47 +75,48 @@ def _state(seed, m, n):
 
 
 @given(m=st.sampled_from([3, 4]), seed=st.integers(0, 2**16), data=st.data())
-@settings(max_examples=10, deadline=None)
+@settings(max_examples=10, deadline=None, derandomize=True)
 def test_edm_md_kind_swap_consistent(m, seed, data):
     n = data.draw(st.sampled_from(_NS[m]))
     p = _points(seed, n)
     outs = [
-        np.asarray(E.edm_md(p, m, rho=2, kind=kind)) for kind in _KINDS[m]
+        np.asarray(_launch("edm_md", kind, m=m)(p)) for kind in _KINDS[m]
     ]
     for kind, o in zip(_KINDS[m][1:], outs[1:]):
         assert np.array_equal(outs[0], o), kind
 
 
+@pytest.mark.time_limit(600)
 @given(m=st.sampled_from([3, 4]), seed=st.integers(0, 2**16), data=st.data())
-@settings(max_examples=10, deadline=None)
+@settings(max_examples=10, deadline=None, derandomize=True)
 def test_ca_md_kind_swap_consistent(m, seed, data):
-    n = data.draw(st.sampled_from(_NS[m]))
+    n = data.draw(st.sampled_from(_CA_NS[m]))
     s = _state(seed, m, n)
     outs = [
-        np.asarray(E.ca_md(s, rho=2, kind=kind)) for kind in _KINDS[m]
+        np.asarray(_launch("ca_md", kind)(s)) for kind in _KINDS[m]
     ]
     for kind, o in zip(_KINDS[m][1:], outs[1:]):
         assert np.array_equal(outs[0], o), kind
 
 
 @given(m=st.sampled_from([3, 4]), seed=st.integers(0, 2**16), data=st.data())
-@settings(max_examples=10, deadline=None)
+@settings(max_examples=10, deadline=None, derandomize=True)
 def test_edm_md_permutation_consistent(m, seed, data):
     n = data.draw(st.sampled_from(_NS[m]))
     perm = data.draw(
         st.sampled_from(list(itertools.permutations(range(m)))[1:])
     )
     p = _points(seed, n)
-    out = np.asarray(E.edm_md(p, m, rho=2, kind="table"))
+    out = np.asarray(_launch("edm_md", "table", m=m)(p))
     np.testing.assert_allclose(
         out, out.transpose(perm), rtol=1e-5, atol=1e-6
     )
 
 
 @given(m=st.sampled_from([3, 4]), seed=st.integers(0, 2**16), data=st.data())
-@settings(max_examples=10, deadline=None)
+@settings(max_examples=10, deadline=None, derandomize=True)
 def test_ca_md_permutation_consistent(m, seed, data):
-    n = data.draw(st.sampled_from(_NS[m]))
+    n = data.draw(st.sampled_from(_CA_NS[m]))
     perm = data.draw(
         st.sampled_from(list(itertools.permutations(range(m)))[1:])
     )
@@ -106,25 +129,25 @@ def test_ca_md_permutation_consistent(m, seed, data):
         s_sym = np.minimum(s_sym, s.transpose(power))
         power = tuple(power[i] for i in perm)
     assert np.array_equal(s_sym, s_sym.transpose(perm))
-    out = np.asarray(E.ca_md(jnp.asarray(s_sym), rho=2, kind="table"))
+    out = np.asarray(_launch("ca_md", "table")(jnp.asarray(s_sym)))
     assert np.array_equal(out, out.transpose(perm))
 
 
 @given(m=st.sampled_from([3, 4]), seed=st.integers(0, 2**16), data=st.data())
-@settings(max_examples=10, deadline=None)
+@settings(max_examples=10, deadline=None, derandomize=True)
 def test_edm_md_split_invariant(m, seed, data):
     n = data.draw(st.sampled_from([6, 12]))
     p = _points(seed, n)
-    a = np.asarray(E.edm_md(p, m, rho=2, kind="composite", split=False))
-    b = np.asarray(E.edm_md(p, m, rho=2, kind="composite", split=True))
+    a = np.asarray(_launch("edm_md", "composite", m=m, split=False)(p))
+    b = np.asarray(_launch("edm_md", "composite", m=m, split=True)(p))
     assert np.array_equal(a, b)
 
 
 @given(seed=st.integers(0, 2**16), data=st.data())
-@settings(max_examples=10, deadline=None)
+@settings(max_examples=10, deadline=None, derandomize=True)
 def test_edm3d_matches_oracle(seed, data):
     n = data.draw(st.sampled_from(_NS[3]))
     p = _points(seed, n)
-    got = np.asarray(E.edm3d(p, rho=2, kind="table"))
+    got = np.asarray(_launch("edm3d", "table")(p))
     want = np.asarray(R.edm3d(p))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)

@@ -16,8 +16,16 @@ Invariants of the fold partition and its sharded CA executor:
 Gated behind the dev-extra skip in ``tests/conftest.py`` —
 deterministic spot checks of the same invariants run unconditionally
 in ``tests/test_simplex_sharding.py``.
+
+The bit-exact property draws derandomized examples and runs each
+sharded launch jitted, built once per (m, k): its seed changes the data
+a compiled launch runs on, never what gets compiled, so the module's
+time is small and the same on every run.
 """
 
+import functools
+
+import jax
 import numpy as np
 
 from conftest import require_dev_extra
@@ -37,6 +45,12 @@ from repro.distributed.simplex_sharding import (
 from repro.kernels.ops import simplex_ca2d, simplex_ca_md
 
 _NS = {2: [8, 12, 16, 20, 32], 3: [4, 6, 8, 12, 16], 4: [4, 6, 8]}
+
+
+@functools.lru_cache(maxsize=None)
+def _sharded_ca(k, kind):
+    """The jitted ``sharded_ca(state, k, kind=kind)``, built once."""
+    return jax.jit(functools.partial(sharded_ca, k=k, kind=kind))
 
 
 @settings(max_examples=60, deadline=None)
@@ -91,7 +105,7 @@ def test_shard_cover_of_walk(m, ni, k, seed):
     )
 
 
-@settings(max_examples=10, deadline=None)
+@settings(max_examples=10, deadline=None, derandomize=True)
 @given(
     m=st.sampled_from([2, 3]),
     k=st.sampled_from([2, 4, 8]),
@@ -109,5 +123,5 @@ def test_sharded_ca_bit_equals_engine(m, k, seed):
         want = np.asarray(simplex_ca2d(state, kind=kind))
     else:
         want = np.asarray(simplex_ca_md(state, kind=kind))
-    got = np.asarray(sharded_ca(state, k, kind=kind))
+    got = np.asarray(_sharded_ca(k, kind)(state))
     assert np.array_equal(want, got)
