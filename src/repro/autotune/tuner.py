@@ -26,7 +26,8 @@ Decision procedure:
 4. **Disk cache** — decisions persist in a JSON cache keyed
    ``m,n,backend``; an entry is invalidated when the JAX version or the
    bench artifact fingerprint (content hash) changes, so fresh
-   measurements re-tune automatically.
+   measurements re-tune automatically.  Attention keys also name the
+   tile rule, so a decision made under another rule is never served.
 
 Env knobs: ``REPRO_AUTOTUNE_CACHE`` (cache file path),
 ``REPRO_BENCH_ARTIFACT`` (bench rows to consume),
@@ -411,17 +412,29 @@ class AttnDecision:
     fingerprint: str
 
 
-_ATTN_BLOCKS = (128, 64, 32, 16, 8)
+# Tile sides, largest first.  A grid step of the flash kernel pays a
+# fixed cost beside its matmuls (about 0.54 us a 128 step on a v5e,
+# against 43 ns of bf16 matmul: PERF.md §6), so a larger tile pays it
+# less often.  The interpreter keeps the 128 cap: its per-step cost is
+# emulation, which no chip measurement speaks for.
+_ATTN_BLOCKS = (512, 256, 128, 64, 32, 16, 8)
+_ATTN_INTERPRET_MAX = 128
+# Part of every attention decision's cache key: change it with the tile
+# rule, so that no decision of another rule is served, nor is one of
+# this rule served to code that keeps another.
+_ATTN_TILE_RULE = "tiles=512"
 
 
 def attn_block_q(seq: int, head_dim: int, backend: Optional[str] = None) -> int:
     """Square attention tile side for a sequence length (0 if none fits).
 
-    Compiled backends take the largest MXU-friendly divisor of ``seq``
-    (biggest tile wins on real hardware).  Interpret backends prefer
-    the largest divisor that still yields at least two query tiles, so
-    the folded simplex walk is actually exercised rather than
-    degenerating to the single-tile bounding box.
+    Compiled backends take the largest aligned divisor of ``seq`` up to
+    512: the biggest tile walks the fewest grid steps, and each step's
+    fixed cost outweighs the larger tile's extra work on the diagonal.
+    Interpret backends take divisors up to 128, and prefer the largest
+    that still yields at least two query tiles, so the folded simplex
+    walk is actually exercised rather than degenerating to the
+    single-tile bounding box.
 
     Args:
         seq: Sequence length.
@@ -435,13 +448,16 @@ def attn_block_q(seq: int, head_dim: int, backend: Optional[str] = None) -> int:
     Example:
         >>> attn_block_q(64, 16, backend="cpu")   # interpret: nq=2 fold
         32
-        >>> attn_block_q(4096, 128, backend="tpu")
+        >>> attn_block_q(8192, 128, backend="tpu")
+        512
+        >>> attn_block_q(1920, 128, backend="tpu")  # 15 x 128: keeps 128
         128
     """
     from repro.kernels.policy import TPU_LANE, TPU_SUBLANE, default_interpret
 
     interpret = default_interpret(backend)
-    divisors = [bq for bq in _ATTN_BLOCKS if bq <= seq and seq % bq == 0]
+    top = _ATTN_INTERPRET_MAX if interpret else seq
+    divisors = [bq for bq in _ATTN_BLOCKS if bq <= top and seq % bq == 0]
     if not interpret:
         divisors = [
             bq for bq in divisors
@@ -578,7 +594,8 @@ def choose_attn_impl(
     disabled = os.environ.get(_DISABLE_ENV, "").strip() == "1"
     bench_file = bench_artifact_path(bench_path)
     cpath = cache_path(cache_file)
-    key = f"attn,s={seq},h={heads},d={head_dim},backend={backend}"
+    key = (f"attn,s={seq},h={heads},d={head_dim},backend={backend},"
+           f"{_ATTN_TILE_RULE}")
     fp = _fingerprint(bench_file)
     jv = _jax_version()
 

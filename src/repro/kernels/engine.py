@@ -74,8 +74,8 @@ from repro.core.schedule import SimplexSchedule, every_step_valid, resolve_kind
 from .policy import (
     aligned_rho,
     check_tile_alignment,
+    compiler_params,
     resolve_interpret,
-    vmem_limit_bytes,
 )
 
 __all__ = [
@@ -639,7 +639,7 @@ def _launch_domain(kernel: "SimplexKernel", body: KernelBody, x):
                 grid_spec=grid_spec,
                 input_output_aliases={alias_src: 0},
                 name=_kernel_name(body.name, sched.kind),
-                **_compiler_params(interpret, block_bytes, rho**m * 4),
+                **compiler_params(interpret, block_bytes, rho**m * 4),
             )(*args, *([state] * len(shifts)), *extras)
     if not trash:
         return state
@@ -650,28 +650,6 @@ def _launch_domain(kernel: "SimplexKernel", body: KernelBody, x):
 def _kernel_name(body: str, kind: str) -> str:
     """The launch's stable kernel name, ``engine_<body>_<kind>``."""
     return f"engine_{body}_{kind}".replace("-", "_")
-
-
-def _compiler_params(interpret: bool, block_bytes: int,
-                     tile_bytes: int) -> dict:
-    """``compiler_params`` for a compiled launch (empty when interpreting).
-
-    Args:
-        interpret: Resolved launch mode.
-        block_bytes: Bytes of one grid step's blocks, inputs and output.
-        tile_bytes: Bytes of one 32-bit ``(rho,)*m`` temporary.
-
-    Returns:
-        Keyword arguments for ``pallas_launch``.
-    """
-    if interpret:
-        return {}
-    from jax.experimental.pallas import tpu as pltpu
-
-    limit = vmem_limit_bytes(block_bytes, tile_bytes)
-    if limit is None:
-        return {}
-    return {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=limit)}
 
 
 def _halo_tiles(halo_refs, shifts, blocks, m, n, nb, rho, boundary):

@@ -33,7 +33,8 @@ The same fold is exposed as ``core.schedule.folded_causal_pairs`` for
 sequence-parallel sharding (equal triangle area per shard).
 
 Block sizes default to TPU-native (block_q x head_dim = 128 x 128 MXU
-tiles); tests sweep smaller shapes in interpret mode.
+tiles); ``autotune.attn_block_q`` picks larger square tiles where the
+sequence allows, and tests sweep smaller shapes in interpret mode.
 """
 
 from __future__ import annotations
@@ -47,7 +48,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .engine import pallas_launch
-from .policy import check_tile_alignment, resolve_interpret
+from .policy import check_tile_alignment, compiler_params, resolve_interpret
 
 NEG_INF = -1e30
 
@@ -443,6 +444,11 @@ def _flash_launch(kind, block_q, block_kv, scale, interpret, q, k, v,
             o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
     check_tile_alignment((block_q, d), interpret, what="q block")
+    # q, k, v and o blocks, plus the optional bias tile and segment rows;
+    # the (bq, bk) f32 scores, probabilities and mask are the temporaries
+    block_bytes = 2 * (block_q + block_kv) * d * q.dtype.itemsize
+    block_bytes += 4 * (block_q * block_kv * has_bias
+                        + (block_q + block_kv) * has_seg)
     qr = q.reshape(b * hq, s, d)
     kr = k.reshape(b * hkv, s, d)
     vr = v.reshape(b * hkv, s, d)
@@ -464,5 +470,6 @@ def _flash_launch(kind, block_q, block_kv, scale, interpret, q, k, v,
         ],
         interpret=interpret,
         name=f"flash_{kind}",
+        **compiler_params(interpret, block_bytes, 4 * block_q * block_kv),
     )(qr, kr, vr, *extra_in)
     return out.reshape(b, hq, s, d)

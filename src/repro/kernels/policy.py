@@ -37,6 +37,7 @@ __all__ = [
     "tile_alignment_ok",
     "aligned_rho",
     "vmem_limit_bytes",
+    "compiler_params",
     "TPU_SUBLANE",
     "TPU_LANE",
 ]
@@ -218,3 +219,26 @@ def vmem_limit_bytes(block_bytes: int, tile_bytes: int) -> Optional[int]:
     if need <= SCOPED_VMEM_DEFAULT:
         return None
     return min(need + need // 4, SCOPED_VMEM_MAX)
+
+
+def compiler_params(interpret: bool, block_bytes: int,
+                    tile_bytes: int) -> dict:
+    """``compiler_params`` for a compiled launch (empty when interpreting).
+
+    Args:
+        interpret: Resolved launch mode.
+        block_bytes: Bytes of one grid step's blocks, inputs and output.
+        tile_bytes: Bytes of one 32-bit tile temporary of the body.
+
+    Returns:
+        Keyword arguments for ``pallas_launch``: a scoped-VMEM limit
+        where ``vmem_limit_bytes`` asks for one, else nothing.
+    """
+    if interpret:
+        return {}
+    limit = vmem_limit_bytes(block_bytes, tile_bytes)
+    if limit is None:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+
+    return {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=limit)}

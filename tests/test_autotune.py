@@ -179,13 +179,49 @@ def test_attn_decision_serve_shape_is_folded_flash(env, monkeypatch):
         "folded", "bb", "chunked"
     }
     data = json.loads(env["cache"].read_text())
-    assert "attn,s=64,h=4,d=16,backend=cpu" in data["entries"]
+    assert f"attn,s=64,h=4,d=16,backend=cpu,{T._ATTN_TILE_RULE}" in data["entries"]
     assert T.choose_attn_impl(64, 4, 16, backend="cpu").source == "cache"
 
 
 def test_attn_decision_compiled_backend_prefers_folded(env):
     d = T.choose_attn_impl(4096, 32, 128, backend="tpu")
-    assert (d.impl, d.kind, d.block_q) == ("flash", "folded", 128)
+    assert (d.impl, d.kind, d.block_q) == ("flash", "folded", 512)
+
+
+@pytest.mark.parametrize("seq,head_dim,block", [
+    (8192, 128, 512),  # yi6b.prefill_8k: nq 16
+    (1024, 128, 512),  # yi6b.prefill_1k: nq 2, the fold still has a pair
+    (1920, 128, 128),  # 15 x 128: no larger tile divides it
+    (8192, 64, 0),  # lane-misaligned head dim: no flash tile
+])
+def test_attn_compiled_tile_rule(env, seq, head_dim, block):
+    assert T.attn_block_q(seq, head_dim, backend="tpu") == block
+    d = T.choose_attn_impl(seq, 32, head_dim, backend="tpu")
+    assert d.block_q == block
+    if block:  # the ranking stays on the folded kernel at the new tile
+        assert (d.impl, d.kind, d.source) == ("flash", "folded", "model")
+    else:
+        assert (d.impl, d.source) == ("chunked", "fallback")
+
+
+def test_attn_entry_of_an_older_tile_rule_is_recomputed(env):
+    # a decision cached under the 128 rule carries the same JAX version
+    # and bench fingerprint; serving its tile would hide the new rule's.
+    old_key = "attn,s=8192,h=32,d=128,backend=tpu"
+    entry = {
+        "impl": "flash", "kind": "folded", "block_q": 128, "source": "model",
+        "score_us": 1.0, "scores_us": {}, "jax_version": T._jax_version(),
+        "fingerprint": T._fingerprint(str(env["bench"])),
+    }
+    env["cache"].write_text(json.dumps(
+        {"schema": T.CACHE_SCHEMA, "entries": {old_key: entry}}))
+    d = T.choose_attn_impl(8192, 32, 128, backend="tpu")
+    assert d.source == "model" and d.block_q == 512
+    again = T.choose_attn_impl(8192, 32, 128, backend="tpu")
+    assert again.source == "cache" and again.block_q == 512
+    # the old rule's entry stays for code that keeps the old rule
+    entries = json.loads(env["cache"].read_text())["entries"]
+    assert entries[old_key]["block_q"] == 128
 
 
 def test_attn_interpret_step_cap_falls_back(env, monkeypatch):
@@ -243,6 +279,8 @@ def test_attn_interpret_measured_rows_are_ignored(env):
 
 def test_attn_block_q_shapes():
     assert T.attn_block_q(64, 16, backend="cpu") == 32  # nq>=2 preferred
-    assert T.attn_block_q(4096, 128, backend="tpu") == 128
+    assert T.attn_block_q(4096, 128, backend="tpu") == 512
+    assert T.attn_block_q(384, 128, backend="tpu") == 128  # 3 x 128
+    assert T.attn_block_q(4096, 16, backend="cpu") == 128  # interpret cap
     assert T.attn_block_q(100, 16, backend="cpu") == 0  # nothing divides
     assert T.attn_block_q(4096, 64, backend="tpu") == 0  # lane misaligned

@@ -87,6 +87,36 @@ def test_flash_attention_compiles(one_chip, kind, s):
     assert "tpu_custom_call" in _compile_text(fn, one_chip, q, kv, kv)
 
 
+@pytest.mark.parametrize("s", [8192, 1024])  # the benchmark's prompts
+def test_flash_attention_compiles_at_chosen_tile(one_chip, s):
+    # the tile the compiled-path rule picks must lower and fit VMEM
+    # (Mosaic refuses a kernel past its scoped limit) at yi-6b widths.
+    from repro.autotune import attn_block_q
+
+    tile = attn_block_q(s, D, backend="tpu")
+    assert tile > 128
+    fn = functools.partial(
+        flash_attention, kind="folded", block_q=tile, block_kv=tile,
+        interpret=False,
+    )
+    q = ((1, HQ, s, D), jnp.bfloat16)
+    kv = ((1, HKV, s, D), jnp.bfloat16)
+    assert "tpu_custom_call" in _compile_text(fn, one_chip, q, kv, kv)
+
+
+def test_flash_attention_large_tile_asks_for_vmem(one_chip):
+    # a 2048 tile's f32 score temporaries outgrow Mosaic's 16 MiB scoped
+    # default; the launch sizes its own limit and compiles.
+    s, tile = 8192, 2048
+    fn = functools.partial(
+        flash_attention, kind="folded", block_q=tile, block_kv=tile,
+        interpret=False,
+    )
+    q = ((1, HQ, s, D), jnp.bfloat16)
+    kv = ((1, HKV, s, D), jnp.bfloat16)
+    assert "tpu_custom_call" in _compile_text(fn, one_chip, q, kv, kv)
+
+
 def test_decode_attention_compiles(one_chip):
     # decode attention is XLA (no Pallas kernel): it must compile and
     # read the cache in place, with no copy of it in temporaries.
@@ -164,7 +194,8 @@ def test_prefill_ops_are_scoped(one_chip, monkeypatch):
     params = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    tokens = jax.ShapeDtypeStruct((1, 256), jnp.int32, sharding=one_chip)
+    # 1024 tokens: two 512 query tiles, so the walk is the fold
+    tokens = jax.ShapeDtypeStruct((1, 1024), jnp.int32, sharding=one_chip)
     text = jax.jit(prefill_step).lower(params, tokens).compile().as_text()
     scopes, opcodes = op_scopes(text), _opcodes(text)
     matmuls = {n: scopes[n] for n, op in opcodes.items()

@@ -71,6 +71,22 @@ def test_flash_vs_chunked_vs_oracle(s, block, gqa, d):
         assert np.array_equal(np.asarray(fl), np.asarray(ch)), kind
 
 
+@pytest.mark.parametrize("gqa", [1, 4])
+def test_flash_tile_size_keeps_oracle_parity(gqa):
+    # the same inputs at two square tiles (nq 2 and 8): only the order
+    # of accumulation changes, so both stay on the float64 oracle.
+    s, hq = 128, 4
+    q, k, v = _qkv(2, hq, hq // gqa, s, 64, seed=11 + gqa)
+    want = np_causal_attention(q, k, v)
+    for block in (s // 2, s // 8):
+        got = flash_attention(
+            q, k, v, kind="folded", block_q=block, block_kv=block
+        )
+        np.testing.assert_allclose(
+            np.asarray(got), want, atol=2e-5, rtol=2e-5, err_msg=str(block)
+        )
+
+
 def test_flash_additive_bias_matches_oracle():
     s, block = 64, 32
     q, k, v = _qkv(2, 4, 1, s, 64, seed=7)
